@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import logging
 import os
 import queue
 import threading
@@ -48,6 +49,8 @@ from repro.api.resilience import (
     ResiliencePolicy,
     WorkerCrashed,
 )
+
+logger = logging.getLogger("repro.api.engine")
 
 #: backend names from most-accelerated to most-conservative; a fallback
 #: chain is the suffix after the primary (see :func:`fallback_chain`)
@@ -336,6 +339,11 @@ class EngineStats:
     #: rows the early-exit adapter accounted (the merge weight; counts
     #: direct ``predict()`` traffic that never enters the request queue)
     n_early_exit_rows: int = 0
+    #: starts whose primary backend failed its warmup, so the engine
+    #: began on a fallback (degraded start)
+    n_degraded_starts: int = 0
+    #: the error that failed the primary's warmup ("" when it did not)
+    primary_start_error: str = ""
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -395,6 +403,7 @@ class EngineStats:
                 / ee_n if ee_n else 0.0
             ),
             n_early_exit_rows=ee_n,
+            n_degraded_starts=sum(p.n_degraded_starts for p in parts),
         )
 
 
@@ -454,6 +463,8 @@ class MicroBatchEngine:
         self._n_crashes = 0
         self._n_retries = 0
         self._n_fallback = 0
+        self._n_degraded_starts = 0
+        self._start_error = ""
         self._active_idx = 0
         self._backoff_rng = np.random.default_rng(self.policy.seed)
 
@@ -518,15 +529,22 @@ class MicroBatchEngine:
         self._active_idx = 0
         # warm the compiled predictor at every bucket shape so steady-state
         # latency never pays a trace (and the stats clock starts after it)
+        self._start_error = ""
         try:
             for b in self._buckets():
                 self._predict(np.zeros((b, self.n_features), np.float32))
-        except Exception:
+        except Exception as exc:
             if len(self._chain) == 1:
                 raise
             # a broken primary with fallbacks available is a degraded
-            # start, not a failed one: trip its breaker and serve on
+            # start, not a failed one: trip its breaker, record why (stats
+            # shows it) and serve on
             self._breakers[0].trip()
+            self._n_degraded_starts += 1
+            self._start_error = repr(exc)
+            logger.warning(
+                "primary backend %r failed its warmup; serving on %r: %r",
+                self._chain[0][0], self._chain[1][0], exc)
         if self._early_exit is not None:
             self._early_exit.reset()  # warmup rows must not skew the mean
         self._t_start = time.perf_counter()
@@ -784,6 +802,8 @@ class MicroBatchEngine:
                 self._early_exit.rows_counted()
                 if self._early_exit is not None else 0
             ),
+            n_degraded_starts=self._n_degraded_starts,
+            primary_start_error=self._start_error,
         )
 
 

@@ -26,39 +26,10 @@ import jax
 import jax.numpy as jnp
 import msgpack
 import numpy as np
+import zstandard
 
-import zlib
-
-try:
-    import zstandard
-except ImportError:  # container without zstd: fall back to stdlib zlib
-    zstandard = None
-
-_ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"  # zstd frame header
-
-
-class _Codec:
-    """zstd when available, zlib otherwise; decompression sniffs the frame
-    magic so checkpoints stay readable across both environments."""
-
-    def __init__(self):
-        self._c = zstandard.ZstdCompressor(level=3) if zstandard else None
-        self._d = zstandard.ZstdDecompressor() if zstandard else None
-
-    def compress(self, data: bytes) -> bytes:
-        return self._c.compress(data) if self._c else zlib.compress(data, 3)
-
-    def decompress(self, data: bytes) -> bytes:
-        if bytes(data[:4]) == _ZSTD_MAGIC:
-            if self._d is None:
-                raise RuntimeError(
-                    "checkpoint shard is zstd-compressed; install 'zstandard' to load it"
-                )
-            return self._d.decompress(data)
-        return zlib.decompress(data)
-
-
-_CCTX = _DCTX = _Codec()
+_CCTX = zstandard.ZstdCompressor(level=3)
+_DCTX = zstandard.ZstdDecompressor()
 
 
 def _path_str(path) -> str:

@@ -18,8 +18,6 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-
-from repro import compat
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -91,7 +89,7 @@ def train_data_parallel(
 
     # probe output structure to build out_specs: everything replicated except
     # the row-sharded per-sample predictions.
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(), P(), P(), P()),

@@ -139,13 +139,15 @@ class EarlyExitPolicy:
         )
 
 
-def decision_final_mask(scores, rem, slack, guard: float = 0.0):
+def decision_final_mask(scores, rem, slack, guard: float = 0.0,
+                        keepdims: bool = False):
     """(n,) bool: rows whose ``predict_label`` can no longer change.
 
     ``scores`` is (n, C); ``rem`` is the (C,) remaining-mass bound row for
-    the current prefix; ``slack`` is (C,) policy slack.  Written with
-    operators only so the same tie rule runs on numpy arrays and inside
-    jax traces (the pallas kernel imports this).
+    the current prefix; ``slack`` is (C,) policy slack (any indexable of C
+    scalars).  Written with operators only so the same tie rule runs on
+    numpy arrays and inside jax traces (the pallas kernel imports this,
+    with ``keepdims=True`` for an (n, 1) mask: Mosaic keeps vectors 2-D).
 
     Binary (C==1, label ``score > 0``): the sign is final when
     ``s - rem > g`` or ``s + rem <= -g``.  Multiclass (``np.argmax``,
@@ -157,19 +159,21 @@ def decision_final_mask(scores, rem, slack, guard: float = 0.0):
     lead.
     """
     C = scores.shape[-1]
+    col = (lambda j: scores[..., j:j + 1]) if keepdims else (
+        lambda j: scores[..., j])
     if C == 1:
-        s = scores[..., 0]
+        s = col(0)
         g = slack[0] + guard * (1.0 + abs(s))
         r = rem[0]
         return ((s - r) > g) | ((s + r) <= -g)
     out = None
     for j in range(C):
-        sj = scores[..., j]
+        sj = col(j)
         cond = None
         for c in range(C):
             if c == j:
                 continue
-            sc = scores[..., c]
+            sc = col(c)
             need = rem[j] + rem[c] + slack[j] + guard * (1.0 + abs(sj) + abs(sc))
             diff = sj - sc
             term = (diff > need) if c < j else (diff >= need)

@@ -1,8 +1,11 @@
 """Pallas TPU kernel: quantile binning (bucketize) of raw features.
 
 ``bin = #{edges < x}`` computed by broadcast-compare against the edge table
-held in VMEM, accumulating over edge chunks to bound the VMEM working set.
-Pure VPU work; the sample tile streams, the edge table is resident.
+held in VMEM.  The table arrives transposed, ``(E, d)``, and padded with
++inf rows to a multiple of 8; the kernel walks it eight edge rows at a time
+(one aligned sublane-tile load per loop step, then static row slices), so
+the working set stays one sample tile.  Pure VPU work; the sample tile
+streams, the edge table is resident.
 """
 
 from __future__ import annotations
@@ -14,23 +17,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 TILE = 512
-EDGE_CHUNK = 32
+EDGE_ROWS = 8
 
 
-def _kernel(x_ref, edges_ref, out_ref, *, n_edges: int):
-    x = x_ref[...]            # (TILE, d)
-    edges = edges_ref[...]    # (d, E)
-    acc = jnp.zeros(x.shape, jnp.int32)
-    n_chunks = -(-n_edges // EDGE_CHUNK)
-    for c in range(n_chunks):
-        lo = c * EDGE_CHUNK
-        width = min(EDGE_CHUNK, n_edges - lo)
-        e = jax.lax.dynamic_slice_in_dim(edges, lo, width, axis=1)  # (d, w)
-        # (TILE, d, w) compare; +inf edges never count
-        acc = acc + jnp.sum(
-            (x[:, :, None] > e[None, :, :]).astype(jnp.int32), axis=-1
-        )
-    out_ref[...] = acc
+def _kernel(x_ref, edges_ref, out_ref):
+    x = x_ref[...]                      # (TILE, d)
+
+    def chunk(c, acc):
+        lo = pl.multiple_of(c * EDGE_ROWS, EDGE_ROWS)
+        e = edges_ref[pl.ds(lo, EDGE_ROWS), :]      # (EDGE_ROWS, d)
+        for r in range(EDGE_ROWS):      # +inf edges never count
+            acc = acc + jnp.where(x > e[r:r + 1, :], 1, 0)
+        return acc
+
+    out_ref[...] = jax.lax.fori_loop(
+        0, edges_ref.shape[0] // EDGE_ROWS, chunk,
+        jnp.zeros(x.shape, jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -39,19 +41,20 @@ def binning(x, edges, *, interpret: bool = True):
     n, d = x.shape
     E = edges.shape[1]
     n_pad = -n % TILE
-    if n_pad:
-        x = jnp.pad(x, ((0, n_pad), (0, 0)))
-    n_tiles = (n + n_pad) // TILE
+    x = jnp.pad(x.astype(jnp.float32), ((0, n_pad), (0, 0)))
+    e_pad = -E % EDGE_ROWS if E else EDGE_ROWS
+    edges_t = jnp.pad(edges.astype(jnp.float32).T, ((0, e_pad), (0, 0)),
+                      constant_values=jnp.inf)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, n_edges=E),
-        grid=(n_tiles,),
+        _kernel,
+        grid=((n + n_pad) // TILE,),
         in_specs=[
             pl.BlockSpec((TILE, d), lambda i: (i, 0)),
-            pl.BlockSpec((d, E), lambda i: (0, 0)),
+            pl.BlockSpec(edges_t.shape, lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((TILE, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + n_pad, d), jnp.int32),
         interpret=interpret,
-    )(x.astype(jnp.float32), edges.astype(jnp.float32))
+    )(x, edges_t)
     return out[:n]

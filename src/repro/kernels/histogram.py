@@ -2,31 +2,32 @@
 
 LightGBM's histogram step is a random scatter-add — hostile to TPUs.  Two
 scatter-free implementations live here, both behind the
-``repro.kernels.ops.build_histogram`` dispatch:
+``repro.kernels.ops.build_histogram`` dispatch, and both compute the same
+contraction: per feature, the bin one-hot against a node-expanded channel
+matrix ``A[s, node*CH + c] = gh[s, c] * [pos[s] == node]``,
 
-``histogram`` (Pallas, TPU-native form, DESIGN.md §3): for a tile of
-samples, build a one-hot ``(tile, n_nodes*n_bins)`` matrix from the
-combined (node, bin) id and contract it with the per-sample channel matrix
-``[g, h, 1]`` on the MXU:
+    hist[node, f, b, c] = sum_s [bins[s, f] == b] * A[s, node*CH + c].
 
-    hist[node*B + b, ch] += sum_s onehot[s, node*B + b] * gh[s, ch]
-
-Grid: (node_chunks, features, sample_tiles) — the sample-tile axis is the
-innermost (fastest) so each (chunk, feature) output block is revisited and
-accumulated in place, a standard Pallas reduction pattern.
-
-Alignment notes (TPU target): TILE=512 samples keeps the one-hot contraction
-MXU-shaped (512×NB @ 512×8); NB = NODE_CHUNK*n_bins is a multiple of 128 for
-n_bins ∈ {64, 128, 256}; channels are padded to 8 lanes by XLA.  fp32
-accumulation throughout.
+``histogram`` (Pallas, the TPU path): the bins arrive transposed, ``(d, n)``,
+as do the channels, ``(CH, n)``, and node ids, ``(1, n)``: samples sit on
+lanes, a grid step reads a ``(FEATURE_BLOCK, TILE)`` bins block, every
+block obeys the (8, 128) tiling rule, and no input is padded to 128 lanes
+in HBM.  The step builds ``A`` (transposed) for its sample tile in VMEM
+and, per feature, the ``(n_bins, TILE)`` one-hot, and contracts them on the
+MXU into the feature's ``(n_bins, nodes*CH)`` output rows.  Grid: (feature blocks,
+sample tiles) — the sample-tile axis is innermost, so each output block is
+revisited and accumulated in place.  The MXU multiplies bf16: each fp32
+channel value is split exactly into three bf16 parts (hi + mid + lo, by
+truncating the mantissa), the parts become separate columns of ``A``, and
+the three partial histograms are summed in fp32 afterwards, so products are
+exact and accumulation is fp32.
 
 ``histogram_fused`` (jnp, the CPU/GPU fast path): the same contraction
 expressed as one ``(n_bins, n) @ (n, n_nodes*CH)`` dot_general per feature.
 Unlike the segment-sum reference it never materializes an ``(n·d, CH)``
 scratch array (XLA's scatter-add is serial on CPU and dominates the
 trainer's hot loop), and unlike the Pallas kernel it needs no
-sample-padding.  The node one-hot is folded into the channel matrix — an
-``(n, n_nodes*CH)`` array built once and reused by all ``d`` features.
+sample-padding.  ``A`` is built once and reused by all ``d`` features.
 
 Shared contract (parity-tested in tests/test_kernels.py): fp32
 accumulation, identical results to ``ref.histogram_ref`` to <= 1e-5, and
@@ -39,42 +40,59 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TILE = 512
-NODE_CHUNK = 8
+FEATURE_BLOCK = 8
+N_PARTS = 3          # bf16 parts per fp32 channel value
+LANES = 128
+#: scoped-VMEM ceiling for the kernel (v5e has 128 MiB of VMEM per core)
+VMEM_CAP = 96 * 1024 * 1024
 
 
-def _kernel(bins_ref, gh_ref, pos_ref, out_ref, *, n_bins: int, node_chunk: int):
-    nc = pl.program_id(0)
-    tile = pl.program_id(2)
+def _bf16_parts(a):
+    """fp32 -> N_PARTS fp32 arrays, each exactly bf16, summing to ``a``.
 
-    bins = bins_ref[...]          # (TILE, 1) int32 — this feature's bin ids
-    gh = gh_ref[...]              # (TILE, CH) float32
-    pos = pos_ref[...]            # (TILE, 1) int32 node-local ids
+    Truncation by bit mask (not a convert round trip, which a compiler may
+    fold away): each part keeps the top 8 significant bits of what is left.
+    """
+    parts = []
+    for _ in range(N_PARTS):
+        bits = jax.lax.bitcast_convert_type(a, jnp.uint32)
+        hi = jax.lax.bitcast_convert_type(
+            bits & jnp.uint32(0xFFFF0000), jnp.float32)
+        parts.append(hi)
+        a = a - hi
+    return parts
 
-    local = pos - nc * node_chunk                       # (TILE, 1)
-    valid = (local >= 0) & (local < node_chunk)
-    ids = local * n_bins + bins                         # (TILE, 1)
-    nb = node_chunk * n_bins
-    iota = jax.lax.broadcasted_iota(jnp.int32, (TILE, nb), 1)
-    onehot = jnp.where((iota == ids) & valid, 1.0, 0.0)  # (TILE, NB) fp32
 
-    # (NB, TILE) @ (TILE, CH) on the MXU
-    acc = jax.lax.dot_general(
-        onehot,
-        gh,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (NB, CH)
-
-    @pl.when(tile == 0)
+def _kernel(bins_ref, gh_ref, pos_ref, colnode_ref, colsel_ref, out_ref, *,
+            n_bins: int):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        out_ref[...] = acc[None, None]
+        out_ref[...] = jnp.zeros(out_ref.shape, jnp.float32)
 
-    @pl.when(tile != 0)
-    def _acc():
-        out_ref[...] += acc[None, None]
+    ch = gh_ref.shape[0]
+    colsel = colsel_ref[...]                        # (Np, 1) part*CH + c
+    vals = jnp.zeros((colsel.shape[0], TILE), jnp.float32)
+    for p, part in enumerate(_bf16_parts(gh_ref[...])):
+        for c in range(ch):
+            vals = jnp.where(colsel == p * ch + c, part[c:c + 1, :], vals)
+    # (Np, TILE) node-expanded channels, transposed; exact in bf16
+    a = jnp.where(colnode_ref[...] == pos_ref[...], vals, 0.0)
+    a = a.astype(jnp.bfloat16)
+
+    bins = bins_ref[...]                            # (FEATURE_BLOCK, TILE)
+    b_iota = jax.lax.broadcasted_iota(jnp.int32, (n_bins, TILE), 0)
+    for j in range(bins.shape[0]):
+        onehot = jnp.where(b_iota == bins[j:j + 1, :], 1.0, 0.0)
+        rows = slice(j * n_bins, (j + 1) * n_bins)
+        # (n_bins, TILE) x (Np, TILE)^T on the MXU -> (n_bins, Np)
+        out_ref[rows, :] += jax.lax.dot_general(
+            onehot.astype(jnp.bfloat16), a, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins", "interpret"))
@@ -87,31 +105,47 @@ def histogram(bins, gh, pos, *, n_nodes: int, n_bins: int, interpret: bool = Tru
     n, d = bins.shape
     CH = gh.shape[1]
     n_pad = -n % TILE
-    if n_pad:
-        bins = jnp.pad(bins, ((0, n_pad), (0, 0)))
-        gh = jnp.pad(gh, ((0, n_pad), (0, 0)))  # zero channels: no contribution
-        pos = jnp.pad(pos, (0, n_pad))
-    n_tiles = (n + n_pad) // TILE
-    n_chunks = -(-n_nodes // NODE_CHUNK)
-    nb = NODE_CHUNK * n_bins
+    d_pad = -d % FEATURE_BLOCK
+    nb = -(-n_bins // 16) * 16                      # bf16 sublane tile
+    # samples on lanes throughout: bins (d, n), channels (CH, n), nodes
+    # (1, n), so a grid step's blocks are (FEATURE_BLOCK, TILE), (CH, TILE)
+    # and (1, TILE), and no array is padded out to 128 lanes in HBM
+    bins_t = jnp.pad(bins.astype(jnp.int32), ((0, n_pad), (0, d_pad))).T
+    gh_t = jnp.pad(gh.astype(jnp.float32), ((0, n_pad), (0, 0))).T
+    # padding rows carry the out-of-range sentinel: they contribute nothing
+    pos = jnp.pad(pos.astype(jnp.int32), (0, n_pad), constant_values=n_nodes)
+
+    n_cols = N_PARTS * n_nodes * CH
+    col = np.arange(-(-n_cols // LANES) * LANES)
+    colnode = np.where(col < n_cols, col // (N_PARTS * CH), -1)[:, None]
+    colsel = (col % (N_PARTS * CH))[:, None]
+    n_cp = col.size
+
+    out_block = FEATURE_BLOCK * nb * n_cp * 4
+    work = 4 * n_cp * TILE * 4 + 2 * nb * TILE * 4 + 2 * n_cp * LANES * 4
+    vmem = min(VMEM_CAP, 2 * out_block + work + (8 << 20))
 
     out = pl.pallas_call(
-        functools.partial(_kernel, n_bins=n_bins, node_chunk=NODE_CHUNK),
-        grid=(n_chunks, d, n_tiles),
+        functools.partial(_kernel, n_bins=nb),
+        grid=((d + d_pad) // FEATURE_BLOCK, (n + n_pad) // TILE),
         in_specs=[
-            pl.BlockSpec((TILE, 1), lambda nc, f, i: (i, f)),
-            pl.BlockSpec((TILE, CH), lambda nc, f, i: (i, 0)),
-            pl.BlockSpec((TILE, 1), lambda nc, f, i: (i, 0)),
+            pl.BlockSpec((FEATURE_BLOCK, TILE), lambda f, i: (f, i)),
+            pl.BlockSpec((CH, TILE), lambda f, i: (0, i)),
+            pl.BlockSpec((1, TILE), lambda f, i: (0, i)),
+            pl.BlockSpec((n_cp, 1), lambda f, i: (0, 0)),
+            pl.BlockSpec((n_cp, 1), lambda f, i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, nb, CH), lambda nc, f, i: (nc, f, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_chunks, d, nb, CH), jnp.float32),
+        out_specs=pl.BlockSpec((FEATURE_BLOCK * nb, n_cp), lambda f, i: (f, 0)),
+        out_shape=jax.ShapeDtypeStruct(((d + d_pad) * nb, n_cp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         interpret=interpret,
-    )(bins.astype(jnp.int32), gh.astype(jnp.float32), pos.astype(jnp.int32)[:, None])
+    )(bins_t, gh_t, pos[None, :], jnp.asarray(colnode, jnp.int32),
+      jnp.asarray(colsel, jnp.int32))
 
-    # (chunks, d, NODE_CHUNK*B, CH) -> (chunks*NODE_CHUNK, d, B, CH) -> trim
-    out = out.reshape(n_chunks, d, NODE_CHUNK, n_bins, CH).transpose(0, 2, 1, 3, 4)
-    out = out.reshape(n_chunks * NODE_CHUNK, d, n_bins, CH)
-    return out[:n_nodes]
+    # (d, nb, cols) -> (d, B, nodes, parts, CH) -> sum parts -> (nodes, d, B, CH)
+    out = out.reshape(d + d_pad, nb, n_cp)[:d, :n_bins, :n_cols]
+    out = out.reshape(d, n_bins, n_nodes, N_PARTS, CH).sum(axis=3)
+    return out.transpose(2, 0, 1, 3)
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins"))
@@ -137,6 +171,7 @@ def histogram_fused(bins, gh, pos, *, n_nodes: int, n_bins: int):
             onehot,
             A,
             dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,  # fp32 products on TPU too
             preferred_element_type=jnp.float32,
         )  # (n_bins, n_nodes*CH)
         return None, out

@@ -1,34 +1,34 @@
 """Pallas TPU kernel: inference over the bit-packed ToaD ensemble.
 
-The compressed model (node words + global threshold/leaf tables) is a few
-KB, so every model array is mapped as a whole-array VMEM block — the TPU
-analogue of the paper's "model fits in MCU RAM".  Per depth step the kernel
+The packed artifact (uint32 node words + global feature / threshold / leaf
+tables) stays the deployment format.  The jitted wrapper decodes it once
+per call, in XLA, into three per-tree tables the kernel can select from
+without gathers:
 
-  1. gathers each lane's current node word,
-  2. decodes (feature_ref, thr_idx) with shifts/masks (VPU integer ops),
-  3. fetches x[feature] and the threshold from the VMEM-resident tables,
-  4. advances ``idx <- 2*idx + 1 + [x > μ]`` (pointer-less traversal).
+  node_feat  (T, I)  raw feature index of each internal node (-1 = no split)
+  node_thr   (T, I)  its threshold value
+  node_leaf  (T, L)  each leaf's value (``leaf_values[leaf_ref]``)
 
-Only the sample tile streams from HBM; traversal never touches HBM, which
-turns tree inference from a memory-bound pointer chase into VPU compute.
+Mosaic lowers no per-lane 1-D gather, so every lookup in the kernel is a
+one-hot compare-and-reduce: per depth step each sample lane selects its
+node's feature and threshold (compare against a node iota, masked sum over
+the node axis), then ``x[feature]`` (compare against a feature iota, masked
+sum over the feature axis), and advances ``idx <- 2*idx + 1 + [x > μ]``
+(pointer-less traversal).  Masked selects, not multiplies, so a NaN or inf
+in an unselected feature never leaks into the sum, and a sum with a single
+non-zero term is exact.  The node tables are a few KB per tree block and
+stay VMEM-resident; only the sample tile streams from HBM.
 
 Tree batching: the grid is 2-D — (sample tiles × tree blocks) — and each
-grid step traverses a block of trees (statically unrolled), so large
-ensembles no longer serialize behind one long per-tree ``fori_loop``: each
-(tile, block) step is an independent unit of work and the per-tree
-bookkeeping (word-row slicing, loop carry) amortizes over the block.  The
-tree-block axis is the innermost grid dimension, so each output tile is
-revisited consecutively and accumulated in place (same reduction pattern
-as the histogram kernel).  Per tree the class accumulation is a column
-scatter-add ``acc.at[:, cls].add(v)`` — one vector update into the class
-column — instead of the dense ``(TILE, C)`` one-hot multiply the
-fori_loop version used.  Trees are round-major (``cls = tree % C``), and
-the block size is ``TREE_BLOCK`` rounded up to a multiple of C, which
-makes ``cls = (block*size + k) % C == k % C`` a *static* column index —
-Mosaic cannot lower a dynamic-index scatter into the lane dimension, a
-static single-column update it can.  The words/leaf arrays are
-zero-padded up to a multiple of the block size; padded trees are masked
-out by the static tree count.
+grid step traverses a block of trees (statically unrolled).  The tree-block
+axis is innermost, so each output tile is revisited consecutively and
+accumulated in place.  Trees are round-major (``cls = tree % C``) and the
+block size is ``TREE_BLOCK`` rounded up to a multiple of C, which makes
+each tree's class column static; the leaf value is added into it through a
+one-hot class mask.  The node tables are laid out ``(n_blocks,
+tree_block, nodes)`` so a block's last two dims equal the array's for any
+``tree_block`` (14 for C=7).  Padded trees have no splits and zero leaves,
+so they add exactly 0.
 """
 
 from __future__ import annotations
@@ -39,9 +39,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TILE = 256
 TREE_BLOCK = 8
+LANES = 128
 
 
 def _decision_final():
@@ -52,64 +54,108 @@ def _decision_final():
     return decision_final_mask
 
 
-def _kernel(
-    x_ref,
-    words_ref,
-    lref_ref,
-    leaf_ref,
-    thr_ref,
-    off_ref,
-    feat_ref,
-    base_ref,
-    out_ref,
-    *,
-    max_depth: int,
-    tidx_bits: int,
-    n_ensembles: int,
-    n_fu: int,
-    n_trees: int,
-    tree_block: int,
-):
-    tb = pl.program_id(1)              # tree-block index (innermost)
+def _tree_block(n_ensembles: int) -> int:
+    """TREE_BLOCK rounded up to a multiple of C: static class columns."""
+    return -(-TREE_BLOCK // n_ensembles) * n_ensembles
 
-    x = x_ref[...]                     # (TILE, d)
-    words = words_ref[...]             # (TREE_BLOCK, I) uint32
-    lref = lref_ref[...]               # (TREE_BLOCK, L) int32
-    leaf_values = leaf_ref[...]        # (V,)
-    thr_table = thr_ref[...]           # (NT,)
-    thr_offsets = off_ref[...]         # (F+1,)
-    used_features = feat_ref[...]      # (F,)
-    base = base_ref[...]               # (C,)
 
-    I = words.shape[1]
-    C = n_ensembles
-    tmask = jnp.uint32((1 << tidx_bits) - 1)
+def _node_tables(words, leaf_ref, leaf_values, thr_table, thr_offsets,
+                 used_features, *, tidx_bits: int, tree_block: int):
+    """Decode packed node words into (n_blocks, tree_block, nodes) tables.
 
-    @pl.when(tb == 0)
-    def _init():
-        out_ref[...] = jnp.broadcast_to(base[None, :], (TILE, C))
+    Returns ``(feat, thr, leaf)`` float32; trees pad to a multiple of
+    ``tree_block`` and the node/leaf axes to a multiple of 128 lanes.
+    Padding has feature -1 (no split) and leaf value 0.
+    """
+    T, I = words.shape
+    n_fu = used_features.shape[0]
+    words = words.astype(jnp.uint32)
+    ref = (words >> tidx_bits).astype(jnp.int32)
+    if n_fu:
+        tix = (words & jnp.uint32((1 << tidx_bits) - 1)).astype(jnp.int32)
+        split = ref < n_fu
+        safe = jnp.minimum(ref, n_fu - 1)
+        feat = jnp.where(split, used_features.astype(jnp.int32)[safe], -1)
+        thr = jnp.where(
+            split, thr_table.astype(jnp.float32)[thr_offsets[safe] + tix], 0.0)
+    else:  # fully-unsplit ensemble: no node ever consults a feature
+        feat = jnp.full((T, I), -1, jnp.int32)
+        thr = jnp.zeros((T, I), jnp.float32)
+    leaf = leaf_values.astype(jnp.float32)[leaf_ref.astype(jnp.int32)]
 
-    acc = jnp.zeros((TILE, C), jnp.float32)
-    for k in range(tree_block):        # static unroll over the tree block
-        row = words[k]                 # (I,)
-        idx = jnp.zeros((TILE,), jnp.int32)
+    t_pad = -T % tree_block
+
+    def blocked(a, fill):
+        a = jnp.pad(a, ((0, t_pad), (0, -a.shape[1] % LANES)),
+                    constant_values=fill)
+        return a.reshape(-1, tree_block, a.shape[1])
+
+    return (blocked(feat.astype(jnp.float32), -1.0), blocked(thr, 0.0),
+            blocked(leaf, 0.0))
+
+
+def _traverse(x, feat_ref, thr_ref, leaf_ref, *, max_depth: int,
+              n_ensembles: int):
+    """(TILE, C) sum of one tree block's leaf values for the sample tile."""
+    tile, d = x.shape
+    tree_block, n_nodes = feat_ref.shape[1:]
+    n_leaves = leaf_ref.shape[2]
+    first_leaf = (1 << max_depth) - 1
+    iota = lambda n: jax.lax.broadcasted_iota(jnp.int32, (tile, n), 1)
+    node_iota, leaf_iota, cls_iota = (
+        iota(n_nodes), iota(n_leaves), iota(n_ensembles))
+    feat_iota = iota(d).astype(jnp.float32)
+
+    def select(mask, table):  # per-lane pick of one table column
+        return jnp.sum(jnp.where(mask, table, 0.0), axis=1, keepdims=True)
+
+    acc = jnp.zeros((tile, n_ensembles), jnp.float32)
+    for k in range(tree_block):
+        feats = feat_ref[0, k:k + 1, :]          # (1, n_nodes)
+        thrs = thr_ref[0, k:k + 1, :]
+        idx = jnp.zeros((tile, 1), jnp.int32)
         for _ in range(max_depth):
-            word = row[idx]
-            ref = (word >> tidx_bits).astype(jnp.int32)
-            tix = (word & tmask).astype(jnp.int32)
-            split = ref < n_fu
-            safe = jnp.minimum(ref, max(n_fu - 1, 0))
-            fidx = used_features[safe]                       # (TILE,)
-            xv = jnp.take_along_axis(x, fidx[:, None], axis=1)[:, 0]
-            thr = thr_table[thr_offsets[safe] + tix]
-            go_left = jnp.where(split, xv <= thr, True)
+            at = node_iota == idx
+            f = select(at, feats)                # (TILE, 1); -1 = no split
+            xv = select(feat_iota == f, x)
+            go_left = (f < 0.0) | (xv <= select(at, thrs))
             idx = 2 * idx + jnp.where(go_left, 1, 2)
-        v = leaf_values[lref[k, idx - I]]                    # (TILE,)
-        live = (tb * tree_block + k < n_trees).astype(jnp.float32)  # pad mask
-        # tree_block % C == 0, so the class column is static (see module doc)
-        acc = acc.at[:, k % C].add(v * live)
+        v = select(leaf_iota == idx - first_leaf, leaf_ref[0, k:k + 1, :])
+        # tree_block % C == 0, so the class column k % C is static
+        acc = acc + jnp.where(cls_iota == k % n_ensembles, v, 0.0)
+    return acc
 
-    out_ref[...] += acc
+
+def _kernel(x_ref, feat_ref, thr_ref, leaf_ref, base_ref, out_ref, *,
+            max_depth: int, n_ensembles: int):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[...] = jnp.broadcast_to(base_ref[...], out_ref.shape)
+
+    out_ref[...] += _traverse(x_ref[...], feat_ref, thr_ref, leaf_ref,
+                              max_depth=max_depth, n_ensembles=n_ensembles)
+
+
+def _specs(d: int, tables, C: int):
+    """BlockSpecs shared by both kernels: sample tile, tree-block tables,
+    base scores."""
+    table = lambda a: pl.BlockSpec((1,) + a.shape[1:], lambda i, t: (t, 0, 0))
+    return [
+        pl.BlockSpec((TILE, d), lambda i, t: (i, 0)),
+        *(table(a) for a in tables),
+        pl.BlockSpec((1, C), lambda i, t: (0, 0)),
+    ]
+
+
+def _prepare(x, words, leaf_ref, leaf_values, thr_table, thr_offsets,
+             used_features, base_score, *, tidx_bits: int, n_ensembles: int):
+    n = x.shape[0]
+    x = jnp.pad(x.astype(jnp.float32), ((0, -n % TILE), (0, 0)))
+    tables = _node_tables(
+        words, leaf_ref, leaf_values, thr_table, thr_offsets, used_features,
+        tidx_bits=tidx_bits, tree_block=_tree_block(n_ensembles))
+    base = base_score.astype(jnp.float32).reshape(1, n_ensembles)
+    return x, tables, base
 
 
 @functools.partial(
@@ -134,74 +180,27 @@ def packed_predict(
     """(n, d) raw floats -> (n, C) ensemble scores from the packed model."""
     n, d = x.shape
     C = n_ensembles
-    T = words.shape[0]
-    if T == 0:  # zero-tree artifact: base scores only
+    if words.shape[0] == 0:  # zero-tree artifact: base scores only
         return jnp.broadcast_to(base_score[None, :].astype(jnp.float32), (n, C))
-    n_pad = -n % TILE
-    if n_pad:
-        x = jnp.pad(x, ((0, n_pad), (0, 0)))
-    n_tiles = (n + n_pad) // TILE
-    # block size: TREE_BLOCK rounded up to a multiple of C, so every class
-    # column index inside a block is static (cls = k % C)
-    tree_block = -(-TREE_BLOCK // C) * C
-    t_pad = -T % tree_block
-    if t_pad:  # padded trees are masked out in-kernel via the static T
-        words = jnp.pad(words, ((0, t_pad), (0, 0)))
-        leaf_ref = jnp.pad(leaf_ref, ((0, t_pad), (0, 0)))
-    n_tblocks = (T + t_pad) // tree_block
-    n_fu = used_features.shape[0]
-    if n_fu == 0:
-        # fully-unsplit ensemble: pad the gather tables (true |F_U| still
-        # reaches the kernel statically, so no node ever reads as split)
-        used_features = jnp.zeros((1,), jnp.int32)
-        thr_table = jnp.zeros((1,), jnp.float32)
-
-    whole = lambda shape: pl.BlockSpec(shape, lambda i, t: (0,) * len(shape))
+    x, tables, base = _prepare(
+        x, words, leaf_ref, leaf_values, thr_table, thr_offsets,
+        used_features, base_score, tidx_bits=tidx_bits, n_ensembles=C)
     out = pl.pallas_call(
-        functools.partial(
-            _kernel,
-            max_depth=max_depth,
-            tidx_bits=tidx_bits,
-            n_ensembles=n_ensembles,
-            n_fu=n_fu,
-            n_trees=T,
-            tree_block=tree_block,
-        ),
-        grid=(n_tiles, n_tblocks),
-        in_specs=[
-            pl.BlockSpec((TILE, d), lambda i, t: (i, 0)),
-            pl.BlockSpec((tree_block, words.shape[1]), lambda i, t: (t, 0)),
-            pl.BlockSpec((tree_block, leaf_ref.shape[1]), lambda i, t: (t, 0)),
-            whole(leaf_values.shape),
-            whole(thr_table.shape),
-            whole(thr_offsets.shape),
-            whole(used_features.shape),
-            whole(base_score.shape),
-        ],
+        functools.partial(_kernel, max_depth=max_depth, n_ensembles=C),
+        grid=(x.shape[0] // TILE, tables[0].shape[0]),
+        in_specs=_specs(d, tables, C),
         out_specs=pl.BlockSpec((TILE, C), lambda i, t: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n + n_pad, C), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((x.shape[0], C), jnp.float32),
         interpret=interpret,
-    )(
-        x.astype(jnp.float32),
-        words.astype(jnp.uint32),
-        leaf_ref.astype(jnp.int32),
-        leaf_values.astype(jnp.float32),
-        thr_table.astype(jnp.float32),
-        thr_offsets.astype(jnp.int32),
-        used_features.astype(jnp.int32),
-        base_score.astype(jnp.float32),
-    )
+    )(x, *tables, base)
     return out[:n]
 
 
 def _kernel_ee(
     x_ref,
-    words_ref,
-    lref_ref,
-    leaf_ref,
-    thr_ref,
-    off_ref,
     feat_ref,
+    thr_ref,
+    leaf_ref,
     base_ref,
     rem_ref,
     slack_ref,
@@ -209,11 +208,8 @@ def _kernel_ee(
     exit_ref,
     *,
     max_depth: int,
-    tidx_bits: int,
     n_ensembles: int,
-    n_fu: int,
     n_trees: int,
-    tree_block: int,
     n_rows: int,
     guard: float,
 ):
@@ -226,64 +222,36 @@ def _kernel_ee(
     exact op sequence of the plain kernel (bit-identical scores), and
     already-exited rows in a still-live tile keep accumulating, which is
     harmless: decision-final means no suffix can change their label.
+    ``rem_ref`` (n_blocks, C) and ``slack_ref`` (1, C) are SMEM scalars; the
+    bound row is picked by the tree-block index.
     """
     i = pl.program_id(0)
     tb = pl.program_id(1)
     C = n_ensembles
+    tree_block = feat_ref.shape[1]
     sentinel = n_trees + 1
-
-    x = x_ref[...]
-    words = words_ref[...]
-    lref = lref_ref[...]
-    leaf_values = leaf_ref[...]
-    thr_table = thr_ref[...]
-    thr_offsets = off_ref[...]
-    used_features = feat_ref[...]
-    base = base_ref[...]
-
-    I = words.shape[1]
-    tmask = jnp.uint32((1 << tidx_bits) - 1)
 
     @pl.when(tb == 0)
     def _init():
-        out_ref[...] = jnp.broadcast_to(base[None, :], (TILE, C))
+        out_ref[...] = jnp.broadcast_to(base_ref[...], out_ref.shape)
         ridx = i * TILE + jax.lax.broadcasted_iota(jnp.int32, (TILE, 1), 0)
         # padding rows "exit" at 0 so they never hold a tile open
         exit_ref[...] = jnp.where(ridx >= n_rows, 0, sentinel)
 
     start = tb * tree_block
-    done = jnp.all(exit_ref[...] <= start)
+    done = jnp.max(exit_ref[...]) <= start
 
     @pl.when(jnp.logical_not(done))
     def _block():
-        acc = jnp.zeros((TILE, C), jnp.float32)
-        for k in range(tree_block):
-            row = words[k]
-            idx = jnp.zeros((TILE,), jnp.int32)
-            for _ in range(max_depth):
-                word = row[idx]
-                ref = (word >> tidx_bits).astype(jnp.int32)
-                tix = (word & tmask).astype(jnp.int32)
-                split = ref < n_fu
-                safe = jnp.minimum(ref, max(n_fu - 1, 0))
-                fidx = used_features[safe]
-                xv = jnp.take_along_axis(x, fidx[:, None], axis=1)[:, 0]
-                thr = thr_table[thr_offsets[safe] + tix]
-                go_left = jnp.where(split, xv <= thr, True)
-                idx = 2 * idx + jnp.where(go_left, 1, 2)
-            v = leaf_values[lref[k, idx - I]]
-            live = (start + k < n_trees).astype(jnp.float32)
-            acc = acc.at[:, k % C].add(v * live)
-        out_ref[...] += acc
-
-        s = out_ref[...]
-        rem = rem_ref[...][0]        # (C,) bound after this block boundary
-        slack = slack_ref[...]       # (C,)
-        fin = _decision_final()(s, rem, slack, guard)      # (TILE,)
+        out_ref[...] += _traverse(x_ref[...], feat_ref, thr_ref, leaf_ref,
+                                  max_depth=max_depth, n_ensembles=C)
+        rem = [rem_ref[tb, c] for c in range(C)]    # bound after this block
+        slack = [slack_ref[0, c] for c in range(C)]
+        fin = _decision_final()(out_ref[...], rem, slack, guard,
+                                keepdims=True)     # (TILE, 1)
         boundary = jnp.minimum(start + tree_block, n_trees)
         cur = exit_ref[...]
-        newly = fin[:, None] & (cur == sentinel)
-        exit_ref[...] = jnp.where(newly, boundary, cur)
+        exit_ref[...] = jnp.where(fin & (cur == sentinel), boundary, cur)
 
 
 @functools.partial(
@@ -312,72 +280,39 @@ def _packed_predict_ee_call(
     guard: float,
     interpret: bool = True,
 ):
-    n, d = x.shape
+    d = x.shape[1]
     C = n_ensembles
-    T = words.shape[0]
-    n_pad = -n % TILE
-    if n_pad:
-        x = jnp.pad(x, ((0, n_pad), (0, 0)))
-    n_tiles = (n + n_pad) // TILE
-    tree_block = -(-TREE_BLOCK // C) * C
-    t_pad = -T % tree_block
-    if t_pad:
-        words = jnp.pad(words, ((0, t_pad), (0, 0)))
-        leaf_ref = jnp.pad(leaf_ref, ((0, t_pad), (0, 0)))
-    n_tblocks = (T + t_pad) // tree_block
-    n_fu = used_features.shape[0]
-    if n_fu == 0:
-        used_features = jnp.zeros((1,), jnp.int32)
-        thr_table = jnp.zeros((1,), jnp.float32)
-
-    whole = lambda shape: pl.BlockSpec(shape, lambda i, t: (0,) * len(shape))
+    x, tables, base = _prepare(
+        x, words, leaf_ref, leaf_values, thr_table, thr_offsets,
+        used_features, base_score, tidx_bits=tidx_bits, n_ensembles=C)
+    n_pad = x.shape[0]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out, exit_tree = pl.pallas_call(
         functools.partial(
             _kernel_ee,
             max_depth=max_depth,
-            tidx_bits=tidx_bits,
-            n_ensembles=n_ensembles,
-            n_fu=n_fu,
-            n_trees=T,
-            tree_block=tree_block,
+            n_ensembles=C,
+            n_trees=words.shape[0],
             n_rows=n_rows,
             guard=guard,
         ),
-        grid=(n_tiles, n_tblocks),
-        in_specs=[
-            pl.BlockSpec((TILE, d), lambda i, t: (i, 0)),
-            pl.BlockSpec((tree_block, words.shape[1]), lambda i, t: (t, 0)),
-            pl.BlockSpec((tree_block, leaf_ref.shape[1]), lambda i, t: (t, 0)),
-            whole(leaf_values.shape),
-            whole(thr_table.shape),
-            whole(thr_offsets.shape),
-            whole(used_features.shape),
-            whole(base_score.shape),
-            pl.BlockSpec((1, C), lambda i, t: (t, 0)),
-            whole(slack.shape),
-        ],
+        grid=(n_pad // TILE, tables[0].shape[0]),
+        in_specs=_specs(d, tables, C) + [smem, smem],
         out_specs=[
             pl.BlockSpec((TILE, C), lambda i, t: (i, 0)),
             pl.BlockSpec((TILE, 1), lambda i, t: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n + n_pad, C), jnp.float32),
-            jax.ShapeDtypeStruct((n + n_pad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n_pad, C), jnp.float32),
+            jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
         ],
         interpret=interpret,
     )(
-        x.astype(jnp.float32),
-        words.astype(jnp.uint32),
-        leaf_ref.astype(jnp.int32),
-        leaf_values.astype(jnp.float32),
-        thr_table.astype(jnp.float32),
-        thr_offsets.astype(jnp.int32),
-        used_features.astype(jnp.int32),
-        base_score.astype(jnp.float32),
+        x, *tables, base,
         rem_blocks.astype(jnp.float32),
-        slack.astype(jnp.float32),
+        slack.astype(jnp.float32).reshape(1, C),
     )
-    return out[:n], exit_tree[:n, 0]
+    return out[:n_rows], exit_tree[:n_rows, 0]
 
 
 def _round_up_f32(x64: np.ndarray) -> np.ndarray:
@@ -425,7 +360,7 @@ def packed_predict_early_exit(
             base_score[None, :].astype(jnp.float32), (n, C))
         return scores, np.zeros(n, np.int32), np.zeros(n, bool)
 
-    tree_block = -(-TREE_BLOCK // C) * C
+    tree_block = _tree_block(C)
     n_tblocks = -(-T // tree_block)
     bound64 = np.asarray(bound, np.float64)
     if bound64.shape != (T + 1, C):

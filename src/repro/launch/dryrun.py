@@ -40,11 +40,11 @@ import re         # noqa: E402
 import time       # noqa: E402
 
 import jax        # noqa: E402
-
-from repro import compat
 import jax.numpy as jnp  # noqa: E402
 import numpy as np       # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -178,7 +178,7 @@ def _lower_one(cfg, mesh, shape, kind):
     nsh = lambda spec: jax.tree.map(
         lambda s: NamedSharding(mesh, s), spec, is_leaf=lambda x: isinstance(x, P)
     )
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             bshapes, bspecs, dp = batch_specs(cfg, mesh, shape)
             opt = get_optimizer(cfg.optimizer, cfg.learning_rate)
@@ -324,7 +324,7 @@ def run_gbdt_cell(multi_pod: bool):
 
     wl = config()
     ndev = 512 if multi_pod else 256
-    mesh = compat.make_mesh((ndev,), ("data",))
+    mesh = make_mesh((ndev,), ("data",))
     rows = wl.rows
     bins = jax.ShapeDtypeStruct((rows, wl.n_features), jnp.int8)
     y = jax.ShapeDtypeStruct((rows,), jnp.float32)
@@ -336,13 +336,13 @@ def run_gbdt_cell(multi_pod: bool):
             hist_dtype=os.environ.get("TOAD_HIST_DTYPE", "f32"),
             hist_quant_bits=int(os.environ.get("TOAD_HIST_QUANT", "0")))
         fn = lambda b, yy, e: train(gcfg, b, yy, e, axis_name="data")
-        sharded = compat.shard_map(
+        sharded = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P("data"), P("data"), P()),
             out_specs=_out_specs(gcfg, "data"),
             check_vma=False,
         )
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jax.jit(sharded).lower(bins, y, edges).compile()
 
     t0 = time.time()

@@ -344,6 +344,9 @@ def main():
     args = ap.parse_args()
     if not args.models:
         ap.error("--models is required")
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     serve_fleet(args)
 
 

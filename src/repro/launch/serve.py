@@ -44,19 +44,19 @@ def serve_lm(args) -> None:
 
     import jax.numpy as jnp
 
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_config, get_reduced
     from repro.models.registry import get_model
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     model = get_model(cfg)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     B, S = args.batch, args.prompt_len
     max_seq = S + args.decode_steps
     key = jax.random.PRNGKey(0)
     params = model.init(key)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if cfg.family == "encdec":
             batch = {
                 "frames": jnp.ones((B, S // cfg.frontend_len_div, cfg.d_model), jnp.bfloat16),
@@ -299,6 +299,9 @@ def main():
     args = ap.parse_args()
 
     from repro.configs import is_gbdt_arch
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.arch in ("toad-fleet", "toad_fleet"):
         from repro.launch.fleet import serve_fleet

@@ -20,7 +20,7 @@ import argparse
 def train_lm(args):
     import jax
 
-    from repro import compat
+    from repro.launch.mesh import make_mesh
 
     from repro.configs import get_config, get_reduced
     from repro.models.registry import get_model
@@ -29,8 +29,8 @@ def train_lm(args):
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     model = get_model(cfg)
     batch_fn = lm_batch_fn(cfg, n_docs=1000, seq=args.seq, batch=args.batch)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    with compat.set_mesh(mesh):
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh):
         params, losses = fit(
             model, batch_fn, steps=args.steps,
             ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
@@ -85,6 +85,9 @@ def main():
     ap.add_argument("--penalty-threshold", type=float, default=1.0)
     ap.add_argument("--forestsize", type=float, default=0.0)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.arch == "toad_gbdt":
         train_gbdt(args)
     else:

@@ -23,8 +23,6 @@ import dataclasses
 from typing import Any
 
 import jax
-
-from repro import compat
 import jax.ad_checkpoint
 import jax.numpy as jnp
 import numpy as np
@@ -186,18 +184,9 @@ def constrain(x, spec: P):
     """with_sharding_constraint that no-ops outside a mesh context (single-
     device smoke tests) and inside shard_map bodies (Manual axes), so the
     same model code runs everywhere."""
-    m = compat.get_abstract_mesh()
-    if m is None or m.empty:
+    m = jax.sharding.get_abstract_mesh()
+    if m.empty or jax.sharding.AxisType.Manual in m.axis_types:
         return x
-    if any("Manual" in str(t) for t in getattr(m, "axis_types", None) or ()):
-        return x
-    if not hasattr(jax.sharding, "get_abstract_mesh"):
-        # old jax: bare specs don't resolve against the resource env under
-        # jit — bind the ambient (physical) mesh explicitly; Manual axes
-        # aren't visible on the physical mesh, so probe the axis env.
-        if compat.in_manual_axes():
-            return x
-        return jax.lax.with_sharding_constraint(x, jax.sharding.NamedSharding(m, spec))
     return jax.lax.with_sharding_constraint(x, spec)
 
 

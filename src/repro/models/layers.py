@@ -8,8 +8,6 @@ G=group size (padded), D=d_model, F=d_ff, E=experts.
 from __future__ import annotations
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -189,7 +187,7 @@ def flash_decode(
         in_specs += [scale_spec, scale_spec]
         out_specs += [scale_spec, scale_spec]
         args += [k_scale, v_scale]
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple(in_specs), out_specs=tuple(out_specs),
         check_vma=False,
@@ -289,7 +287,7 @@ def moe_block(x, w_router, w_in, w_gate, w_out, *, top_k, capacity_factor):
     collectives -> this).
     """
     E = w_in.shape[0]
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or "model" not in mesh.axis_names:
         return _moe_local(
             x, w_router, w_in, w_gate, w_out,
@@ -308,7 +306,7 @@ def moe_block(x, w_router, w_in, w_gate, w_out, *, top_k, capacity_factor):
         )
         return jax.lax.psum(out, "model")
 
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

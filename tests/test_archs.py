@@ -3,8 +3,6 @@ CPU, asserting output shapes + no NaNs; plus decode-vs-prefill parity for
 one arch per family."""
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -37,7 +35,7 @@ def test_smoke_train_step(name, mesh11):
     model = get_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     batch = _batch(cfg)
-    with compat.set_mesh(mesh11):
+    with jax.set_mesh(mesh11):
         loss, grads = jax.jit(
             lambda p, b: jax.value_and_grad(lambda q: model.train_loss(q, b))(p)
         )(params, batch)
@@ -52,7 +50,7 @@ def test_smoke_prefill_decode(name, mesh11):
     model = get_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     batch = _batch(cfg, with_labels=False)
-    with compat.set_mesh(mesh11):
+    with jax.set_mesh(mesh11):
         logits, cache = jax.jit(lambda p, b: model.prefill(p, b))(params, batch)
         assert logits.shape == (B, cfg.padded_vocab)
         assert bool(jnp.all(jnp.isfinite(logits[:, : cfg.vocab])))
@@ -80,7 +78,7 @@ def test_decode_matches_prefill(name, mesh11):
     model = get_model(cfg)
     params = model.init(jax.random.PRNGKey(1))
     toks = jax.random.randint(jax.random.PRNGKey(2), (B, S), 0, cfg.vocab)
-    with compat.set_mesh(mesh11):
+    with jax.set_mesh(mesh11):
         logits, cache = jax.jit(lambda p, b: model.prefill(p, b))(params, {"tokens": toks})
 
         def grow(x):
@@ -156,7 +154,7 @@ def test_int8_kv_cache_parity(mesh11):
         model = get_model(cfg)
         params = model.init(jax.random.PRNGKey(1))
         toks = jax.random.randint(jax.random.PRNGKey(2), (B, S), 0, cfg.vocab)
-        with compat.set_mesh(mesh11):
+        with jax.set_mesh(mesh11):
             logits, cache = jax.jit(lambda p, b: model.prefill(p, b))(
                 params, {"tokens": toks}
             )

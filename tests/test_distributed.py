@@ -4,8 +4,6 @@ checkpoint round-trip + elastic resharding, crash/resume."""
 import os
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -13,6 +11,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.gbdt import GBDTConfig, apply_bins, fit_bins, predict_binned, train_jit
 from repro.gbdt.distributed import train_data_parallel
+from repro.launch.mesh import make_mesh
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 4, reason="needs 4 host devices (see conftest XLA_FLAGS)"
@@ -64,7 +63,7 @@ def test_ef_quantized_psum_unbiased_over_steps():
     xs = rng.normal(size=(4, 64)).astype(np.float32)
 
     @partial(
-        compat.shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
+        jax.shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
         out_specs=(P("data"), P("data")), check_vma=False,
     )
     def step(x, err):
@@ -101,7 +100,7 @@ def test_checkpoint_roundtrip_and_reshard(tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     # elastic: restore onto a 2x2 mesh with a different sharding
-    mesh = compat.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     shardings = {
         "w": NamedSharding(mesh, P("data", "model")),
         "nested": {"b": NamedSharding(mesh, P(None))},
@@ -122,8 +121,8 @@ def test_crash_resume_bit_exact(tmp_path):
     cfg = get_reduced("qwen3-4b")
     model = get_model(cfg)
     batch_fn = lm_batch_fn(cfg, n_docs=100, seq=16, batch=2)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    with compat.set_mesh(mesh):
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh):
         p_full, losses_full = fit(model, batch_fn, steps=6, ckpt_dir=None)
         d1 = str(tmp_path / "run")
         fit(model, batch_fn, steps=4, ckpt_dir=d1, ckpt_every=2)  # "crashes" at 4

@@ -2,14 +2,13 @@
 collective parsing, probe algebra, and a tiny-mesh lower+compile."""
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch.dryrun import _combine, parse_collectives
+from repro.launch.mesh import make_mesh
 from repro.launch.input_specs import SHAPES, batch_specs, skip_reason
 
 
@@ -72,7 +71,7 @@ def test_tiny_mesh_lower_compile_train():
     from repro.train.optimizer import get_optimizer
 
     cfg = get_reduced("qwen3-4b")
-    mesh = compat.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     model = get_model(cfg)
     pshapes, pspecs = model.abstract_init()
     opt = get_optimizer(cfg.optimizer, cfg.learning_rate)
@@ -88,7 +87,7 @@ def test_tiny_mesh_lower_compile_train():
     }
     bspecs = {"tokens": P(("data",), None), "labels": P(("data",), None)}
     fn = make_train_step(model, opt, ("data",))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             fn,
             in_shardings=(nsh(pspecs), nsh(ospecs), NamedSharding(mesh, P()), nsh(bspecs)),
@@ -104,14 +103,14 @@ def test_tiny_mesh_lower_compile_decode():
     from repro.models.registry import get_model
 
     cfg = get_reduced("qwen3-4b")
-    mesh = compat.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     model = get_model(cfg)
     pshapes, pspecs = model.abstract_init()
     cshapes, cspecs = model.abstract_cache(4, 64)
     nsh = lambda spec: jax.tree.map(
         lambda s: NamedSharding(mesh, s), spec, is_leaf=lambda x: isinstance(x, P)
     )
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn = lambda params, cache, token, p: model.decode_step(
             mesh, params, cache, token, p, ("data",)
         )
@@ -127,3 +126,33 @@ def test_tiny_mesh_lower_compile_decode():
         )
         compiled = lowered.compile()
     assert compiled is not None
+
+
+def test_compile_cache_dir_from_env(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX reads it itself: the helper
+    returns it and sets no other directory."""
+    from repro.launch.compile_cache import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_dir_default_is_fixed_and_ignored(monkeypatch):
+    """Unset, the cache goes to one fixed directory inside the checkout,
+    which git ignores."""
+    import pathlib
+
+    from repro.launch.compile_cache import DEFAULT_DIR, enable_compile_cache
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert enable_compile_cache() == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    ignored = (repo / ".gitignore").read_text().splitlines()
+    assert ".jax_cache/" in ignored

@@ -316,6 +316,8 @@ def test_breaker_half_open_recovers_primary():
     assert s.active_backend == "flaky"              # probe succeeded
     assert s.breaker_state["flaky"] == "closed"
     assert s.n_fallback_batches >= 1                # degraded service first
+    assert s.n_degraded_starts == 1                 # the failed warmup shows
+    assert "transient kernel fault" in s.primary_start_error
 
 
 def test_all_breakers_open_still_attempts_last_resort():

@@ -1,0 +1,121 @@
+"""Compile-only checks: the main path's Pallas kernels lower for a TPU v5e.
+
+The TPU compiler is installed even where no chip is attached: a described
+``v5e:2x2`` topology lets ``jit(...).lower(...).compile()`` run Mosaic on
+each kernel at real widths (d=256 features, 256 bins, depth 8, 64 trees).
+Interpret-mode tests cannot see what this catches: blocks that break the
+(8, 128) tiling rule, primitives Mosaic cannot lower, scoped-VMEM overflow.
+Every call passes ``interpret=False`` itself — the backend here is the CPU,
+so the program's own ``interpret`` gate would pick the interpreter.
+
+The topology is described inside a module-scoped fixture, never at import,
+so test workers that never run this file never load the TPU library.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.binning import binning
+from repro.kernels.histogram import histogram
+from repro.kernels.predict import _packed_predict_ee_call, packed_predict
+
+D, N_BINS, DEPTH, N_TREES = 256, 256, 8, 64
+ROWS = 4096
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
+    # a compile for a described chip cannot be read back from the cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _packed_shapes(C: int):
+    n_fu = 64
+    T = N_TREES * C
+    return [
+        ((ROWS, D), jnp.float32),                          # x
+        ((T, 2**DEPTH - 1), jnp.uint32),                   # words
+        ((T, 2**DEPTH), jnp.int32),                        # leaf_ref
+        ((4096,), jnp.float32),                            # leaf_values
+        ((n_fu * (N_BINS - 1),), jnp.float32),             # thr_table
+        ((n_fu + 1,), jnp.int32),                          # thr_offsets
+        ((n_fu,), jnp.int32),                              # used_features
+        ((C,), jnp.float32),                               # base_score
+    ]
+
+
+@pytest.mark.parametrize("C", [1, 7])
+def test_packed_predict_compiles(one_chip, C):
+    fn = functools.partial(
+        packed_predict, max_depth=DEPTH, tidx_bits=8, n_ensembles=C,
+        interpret=False)
+    _assert_kernel(_compile(fn, *_packed_shapes(C), sharding=one_chip))
+
+
+@pytest.mark.parametrize("C", [1, 7])
+def test_packed_predict_early_exit_compiles(one_chip, C):
+    T = N_TREES * C
+    tree_block = -(-8 // C) * C
+    fn = functools.partial(
+        _packed_predict_ee_call, max_depth=DEPTH, tidx_bits=8, n_ensembles=C,
+        n_rows=ROWS, guard=0.0, interpret=False)
+    shapes = _packed_shapes(C) + [
+        ((-(-T // tree_block), C), jnp.float32),           # rem_blocks
+        ((C,), jnp.float32),                               # slack
+    ]
+    _assert_kernel(_compile(fn, *shapes, sharding=one_chip))
+
+
+@pytest.mark.parametrize("n_nodes", [1, 64, 128])  # 128: depth 8, no subtraction
+def test_histogram_compiles(one_chip, n_nodes):
+    fn = functools.partial(
+        histogram, n_nodes=n_nodes, n_bins=N_BINS, interpret=False)
+    compiled = _compile(
+        fn, ((ROWS, D), jnp.int32), ((ROWS, 3), jnp.float32),
+        ((ROWS,), jnp.int32), sharding=one_chip)
+    _assert_kernel(compiled)
+
+
+def test_binning_compiles(one_chip):
+    fn = functools.partial(binning, interpret=False)
+    compiled = _compile(
+        fn, ((ROWS, D), jnp.float32), ((D, N_BINS - 1), jnp.float32),
+        sharding=one_chip)
+    _assert_kernel(compiled)
+
+
+def test_shapes_are_real_widths():
+    """The compile tests above run at the widths of configs/toad_gbdt.py."""
+    from repro.configs.toad_gbdt import config
+
+    cfg = config()
+    assert (cfg.n_features, cfg.n_bins, cfg.gbdt.max_depth) == (D, N_BINS, DEPTH)
+    assert np.log2(ROWS) % 1 == 0
