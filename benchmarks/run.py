@@ -23,7 +23,7 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import appd_random_forest, fig4_quality_memory, fig5_penalty_grid
-    from benchmarks import fig6_univariate, fig7_multivariate, roofline, table2_latency
+    from benchmarks import fig6_univariate, fig7_multivariate, table2_latency
 
     summary = []
 
@@ -52,7 +52,6 @@ def main() -> None:
     bench("fig5", lambda: fig5_penalty_grid.run_fig5(verbose=False))
     bench("appd_rf", lambda: appd_random_forest.run(verbose=False))
     bench("table2", lambda: table2_latency.run(verbose=False))
-    bench("roofline", lambda: roofline.main(verbose=False))
 
     def serve_bench():
         # end-to-end GBDT serving through the micro-batching engine
@@ -125,12 +124,6 @@ def main() -> None:
                 f"mean_trees={h['mean_trees_evaluated']:.1f}"
                 f"/{out['shape']['n_trees']} "
                 f"mismatches={h['label_mismatches']}")
-        elif name == "roofline" and out:
-            ok = [r for r in out if r.get("status") == "OK" and r.get("mfu_floor") == r.get("mfu_floor")]
-            if ok:
-                best = max(ok, key=lambda r: r.get("mfu_floor", 0))
-                derived = (f"cells={len(ok)} best_mfu_floor={best['mfu_floor']:.1%}"
-                           f" ({best['arch']}/{best['shape']})")
         print(f"{name},{dt*1e6:.0f},{derived}")
 
 

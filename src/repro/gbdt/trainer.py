@@ -38,6 +38,23 @@ always fp32 and the count channel is never rounded, so
 ``min_child_samples``/``min_child_weight`` gating stays exact.  (It no
 longer shrinks memory or wire bytes — use ``hist_quant_bits`` for cheap
 histogram collectives.)
+
+Device phases (``PHASES``): every operation of a round runs under one
+``jax.named_scope``, which XLA keeps in each instruction's
+``metadata={op_name=...}``, so a profiler trace attributes device time by
+phase.  A scope is trace-time metadata: the compiled program is the same.
+
+  toad.grad    gradients and hessians; the (n, 3) histogram channels
+  toad.hist    histogram build (Pallas call ``histogram`` and its wrapper's
+               pads, transposes and part sums), sibling subtraction,
+               cross-shard reduction
+  toad.split   cumulative sums, node totals, gains, validity
+  toad.commit  the sequential per-node commit loop
+  toad.route   routing samples to children, dead-node bookkeeping
+  toad.leaf    leaf statistics, leaf values, the shared-table insert loop,
+               per-sample contributions
+  toad.update  tree writes, prediction update, ToaD size, acceptance, state
+               merge, per-round history
 """
 
 from __future__ import annotations
@@ -52,6 +69,11 @@ from repro.core.memory import toad_bits
 from repro.gbdt.forest import Forest
 from repro.gbdt.losses import make_loss
 from repro.kernels.ops import build_histogram, sibling_subtraction_histograms
+
+#: the ``jax.named_scope`` of each phase of a round (module docstring)
+GRAD, HIST, SPLIT, COMMIT, ROUTE, LEAF, UPDATE = PHASES = (
+    "toad.grad", "toad.hist", "toad.split", "toad.commit", "toad.route",
+    "toad.leaf", "toad.update")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,15 +124,18 @@ def _grow_tree(cfg: GBDTConfig, bins, g, h, edges, state, reduce_fn=None):
     I = 2**D - 1
     L = 2**D
     lam = cfg.reg_lambda
-    valid_edge = jnp.isfinite(edges)  # (d, E)
+    with jax.named_scope(SPLIT):
+        valid_edge = jnp.isfinite(edges)  # (d, E)
 
-    t_feat = jnp.zeros((I,), jnp.int32)
-    t_thr = jnp.zeros((I,), jnp.int32)
-    t_split = jnp.zeros((I,), bool)
-    t_gain = jnp.zeros((I,), jnp.float32)  # recorded for CCP post-pruning
-    pos = jnp.zeros((n,), jnp.int32)
-    dead = jnp.zeros((1,), bool)
-    n_splits = jnp.zeros((), jnp.int32)
+    with jax.named_scope(COMMIT):
+        t_feat = jnp.zeros((I,), jnp.int32)
+        t_thr = jnp.zeros((I,), jnp.int32)
+        t_split = jnp.zeros((I,), bool)
+        t_gain = jnp.zeros((I,), jnp.float32)  # recorded for CCP post-pruning
+        n_splits = jnp.zeros((), jnp.int32)
+    with jax.named_scope(ROUTE):
+        pos = jnp.zeros((n,), jnp.int32)
+        dead = jnp.zeros((1,), bool)
 
     # Loop-invariant histogram inputs, hoisted out of the level loop.  bins
     # keep their storage dtype (int8 preferred: 4x less HBM traffic than
@@ -118,68 +143,71 @@ def _grow_tree(cfg: GBDTConfig, bins, g, h, edges, state, reduce_fn=None):
     # hist_dtype="bf16" rounds g/h here (numerics ablation only);
     # accumulation stays fp32 and the count channel is exact regardless.
     hdt = jnp.bfloat16 if cfg.hist_dtype == "bf16" else jnp.float32
-    gh = jnp.stack(
-        [
-            g.astype(hdt).astype(jnp.float32),
-            h.astype(hdt).astype(jnp.float32),
-            jnp.ones((n,), jnp.float32),
-        ],
-        axis=-1,
-    )  # (n, 3)
+    with jax.named_scope(GRAD):
+        gh = jnp.stack(
+            [
+                g.astype(hdt).astype(jnp.float32),
+                h.astype(hdt).astype(jnp.float32),
+                jnp.ones((n,), jnp.float32),
+            ],
+            axis=-1,
+        )  # (n, 3)
     hist_method = None if cfg.hist_method == "auto" else cfg.hist_method
     parent_hist = None
 
     for level in range(D):
         n_nodes = 2**level
         base_idx = n_nodes - 1
-        node_local = pos - base_idx  # (n,) in [0, n_nodes)
 
         # --- gradient/hessian/count histograms: (nodes, d, B, 3) -----------
         # data-parallel training: one all-reduce of the histogram per level
         # (left children only under sibling subtraction) — the
         # distributed-LightGBM pattern.
-        if level >= 1 and cfg.hist_subtract:
-            hist = sibling_subtraction_histograms(
-                bins, gh, node_local, parent_hist, n_bins=B,
-                method=hist_method, reduce_fn=shard_reduce,
-            )
-        else:
-            hist = reduce_fn(
-                build_histogram(
-                    bins, gh, node_local, n_nodes=n_nodes, n_bins=B,
-                    method=hist_method,
+        with jax.named_scope(HIST):
+            node_local = pos - base_idx  # (n,) in [0, n_nodes)
+            if level >= 1 and cfg.hist_subtract:
+                hist = sibling_subtraction_histograms(
+                    bins, gh, node_local, parent_hist, n_bins=B,
+                    method=hist_method, reduce_fn=shard_reduce,
                 )
-            )
+            else:
+                hist = reduce_fn(
+                    build_histogram(
+                        bins, gh, node_local, n_nodes=n_nodes, n_bins=B,
+                        method=hist_method,
+                    )
+                )
         parent_hist = hist
-        G, H, CNT = hist[..., 0], hist[..., 1], hist[..., 2]
 
         # --- standard gain for every (node, feature, edge) ------------------
-        GL = jnp.cumsum(G, axis=-1)[..., :E]
-        HL = jnp.cumsum(H, axis=-1)[..., :E]
-        CL = jnp.cumsum(CNT, axis=-1)[..., :E]
-        # node totals are identical across features — reduce feature 0 once
-        totG = jnp.sum(G[:, 0, :], axis=-1)  # (nodes,)
-        totH = jnp.sum(H[:, 0, :], axis=-1)
-        totC = jnp.sum(CNT[:, 0, :], axis=-1)
-        GR = totG[:, None, None] - GL
-        HR = totH[:, None, None] - HL
-        CR = totC[:, None, None] - CL
-        gain = (
-            0.5
-            * (
-                GL**2 / (HL + lam)
-                + GR**2 / (HR + lam)
-                - (totG**2 / (totH + lam))[:, None, None]
+        with jax.named_scope(SPLIT):
+            G, H, CNT = hist[..., 0], hist[..., 1], hist[..., 2]
+            GL = jnp.cumsum(G, axis=-1)[..., :E]
+            HL = jnp.cumsum(H, axis=-1)[..., :E]
+            CL = jnp.cumsum(CNT, axis=-1)[..., :E]
+            # node totals are identical across features — reduce feature 0 once
+            totG = jnp.sum(G[:, 0, :], axis=-1)  # (nodes,)
+            totH = jnp.sum(H[:, 0, :], axis=-1)
+            totC = jnp.sum(CNT[:, 0, :], axis=-1)
+            GR = totG[:, None, None] - GL
+            HR = totH[:, None, None] - HL
+            CR = totC[:, None, None] - CL
+            gain = (
+                0.5
+                * (
+                    GL**2 / (HL + lam)
+                    + GR**2 / (HR + lam)
+                    - (totG**2 / (totH + lam))[:, None, None]
+                )
+                - cfg.gamma
             )
-            - cfg.gamma
-        )
-        valid = (
-            (CL >= cfg.min_child_samples)
-            & (CR >= cfg.min_child_samples)
-            & (HL >= cfg.min_child_weight)
-            & (HR >= cfg.min_child_weight)
-            & valid_edge[None, :, :]
-        )
+            valid = (
+                (CL >= cfg.min_child_samples)
+                & (CR >= cfg.min_child_samples)
+                & (HL >= cfg.min_child_weight)
+                & (HR >= cfg.min_child_weight)
+                & valid_edge[None, :, :]
+            )
 
         # --- sequential (greedy) commit: later nodes see earlier nodes' ----
         # --- newly used features/thresholds, per the paper's used sets  ----
@@ -205,67 +233,74 @@ def _grow_tree(cfg: GBDTConfig, bins, g, h, edges, state, reduce_fn=None):
             used_thr = used_thr.at[f, e].set(used_thr[f, e] | ok)
             return used_feat, used_thr, t_feat, t_thr, t_split, t_gain, n_splits + ok
 
-        used_feat, used_thr, t_feat, t_thr, t_split, t_gain, n_splits = jax.lax.fori_loop(
-            0,
-            n_nodes,
-            commit,
-            (used_feat, used_thr, t_feat, t_thr, t_split, t_gain, n_splits),
-        )
+        with jax.named_scope(COMMIT):
+            used_feat, used_thr, t_feat, t_thr, t_split, t_gain, n_splits = (
+                jax.lax.fori_loop(
+                    0,
+                    n_nodes,
+                    commit,
+                    (used_feat, used_thr, t_feat, t_thr, t_split, t_gain, n_splits),
+                )
+            )
 
         # --- route samples (unsplit nodes route left) -----------------------
-        f_n = t_feat[pos]
-        e_n = t_thr[pos]
-        s_n = t_split[pos]
-        xb = jnp.take_along_axis(bins, f_n[:, None], axis=1)[:, 0].astype(jnp.int32)
-        go_left = jnp.where(s_n, xb <= e_n, True)
-        pos = 2 * pos + jnp.where(go_left, 1, 2)
+        with jax.named_scope(ROUTE):
+            f_n = t_feat[pos]
+            e_n = t_thr[pos]
+            s_n = t_split[pos]
+            xb = jnp.take_along_axis(bins, f_n[:, None], axis=1)[:, 0].astype(jnp.int32)
+            go_left = jnp.where(s_n, xb <= e_n, True)
+            pos = 2 * pos + jnp.where(go_left, 1, 2)
 
-        # left child of a live unsplit node stays live (may split later once
-        # penalties have been paid by other nodes); right child is dead.
-        split_lvl = jax.lax.dynamic_slice_in_dim(t_split, base_idx, n_nodes)
-        dead = jnp.stack([dead, dead | ~split_lvl], axis=1).reshape(-1)
+            # left child of a live unsplit node stays live (may split later
+            # once penalties have been paid by other nodes); right child is
+            # dead.
+            split_lvl = jax.lax.dynamic_slice_in_dim(t_split, base_idx, n_nodes)
+            dead = jnp.stack([dead, dead | ~split_lvl], axis=1).reshape(-1)
 
     # ---------------- leaves ------------------------------------------------
-    leaf_local = pos - (2**D - 1)
-    leaf_stats = reduce_fn(
-        jax.ops.segment_sum(
-            jnp.stack([g, h, jnp.ones_like(g)], axis=-1), leaf_local, num_segments=L
+    with jax.named_scope(LEAF):
+        leaf_local = pos - (2**D - 1)
+        leaf_stats = reduce_fn(
+            jax.ops.segment_sum(
+                jnp.stack([g, h, jnp.ones_like(g)], axis=-1), leaf_local,
+                num_segments=L,
+            )
         )
-    )
-    G_leaf, H_leaf, C_leaf = leaf_stats[:, 0], leaf_stats[:, 1], leaf_stats[:, 2]
-    raw_v = jnp.where(
-        C_leaf > 0, -cfg.learning_rate * G_leaf / (H_leaf + lam), 0.0
-    ).astype(jnp.float32)
-    if cfg.leaf_quant > 0:
-        raw_v = jnp.round(raw_v / cfg.leaf_quant) * cfg.leaf_quant
-    reachable = ~dead  # (L,) leaf-level liveness
+        G_leaf, H_leaf, C_leaf = leaf_stats[:, 0], leaf_stats[:, 1], leaf_stats[:, 2]
+        raw_v = jnp.where(
+            C_leaf > 0, -cfg.learning_rate * G_leaf / (H_leaf + lam), 0.0
+        ).astype(jnp.float32)
+        if cfg.leaf_quant > 0:
+            raw_v = jnp.round(raw_v / cfg.leaf_quant) * cfg.leaf_quant
+        reachable = ~dead  # (L,) leaf-level liveness
 
-    V = leaf_values.shape[0]
+        V = leaf_values.shape[0]
 
-    def insert(j, carry):
-        leaf_values, n_leaf, lref = carry
-        v = raw_v[j]
-        valid_slot = jnp.arange(V) < n_leaf
-        diffs = jnp.where(valid_slot, jnp.abs(leaf_values - v), jnp.inf)
-        best = jnp.argmin(diffs).astype(jnp.int32)
-        match = diffs[best] <= cfg.leaf_match_tol
-        can_append = n_leaf < V
-        reach = reachable[j]
-        use_new = reach & ~match & can_append
-        ref = jnp.where(match | ~can_append, best, n_leaf)
-        ref = jnp.where(reach, ref, 0).astype(jnp.int32)
-        appended = leaf_values.at[n_leaf].set(v)
-        leaf_values = jnp.where(use_new, appended, leaf_values)
-        n_leaf = n_leaf + use_new.astype(jnp.int32)
-        return leaf_values, n_leaf, lref.at[j].set(ref)
+        def insert(j, carry):
+            leaf_values, n_leaf, lref = carry
+            v = raw_v[j]
+            valid_slot = jnp.arange(V) < n_leaf
+            diffs = jnp.where(valid_slot, jnp.abs(leaf_values - v), jnp.inf)
+            best = jnp.argmin(diffs).astype(jnp.int32)
+            match = diffs[best] <= cfg.leaf_match_tol
+            can_append = n_leaf < V
+            reach = reachable[j]
+            use_new = reach & ~match & can_append
+            ref = jnp.where(match | ~can_append, best, n_leaf)
+            ref = jnp.where(reach, ref, 0).astype(jnp.int32)
+            appended = leaf_values.at[n_leaf].set(v)
+            leaf_values = jnp.where(use_new, appended, leaf_values)
+            n_leaf = n_leaf + use_new.astype(jnp.int32)
+            return leaf_values, n_leaf, lref.at[j].set(ref)
 
-    leaf_values, n_leaf, lref = jax.lax.fori_loop(
-        0, L, insert, (leaf_values, n_leaf, jnp.zeros((L,), jnp.int32))
-    )
+        leaf_values, n_leaf, lref = jax.lax.fori_loop(
+            0, L, insert, (leaf_values, n_leaf, jnp.zeros((L,), jnp.int32))
+        )
 
-    # per-sample contribution of this tree (through the shared table, so any
-    # lossy reuse is reflected in subsequent gradients)
-    contrib = leaf_values[lref[leaf_local]]
+        # per-sample contribution of this tree (through the shared table, so
+        # any lossy reuse is reflected in subsequent gradients)
+        contrib = leaf_values[lref[leaf_local]]
 
     new_state = (used_feat, used_thr, leaf_values, n_leaf, pen_f, pen_t)
     tree = (t_feat, t_thr, t_split, lref, t_gain, C_leaf)
@@ -369,7 +404,8 @@ def train(
     )
 
     def round_body(state, r):
-        g_all, h_all = loss.grad_hess(y, state.get("preds"))
+        with jax.named_scope(GRAD):
+            g_all, h_all = loss.grad_hess(y, state.get("preds"))
         tree_state = (
             state["used_feat"],
             state["used_thr"],
@@ -385,64 +421,66 @@ def train(
             tree, contrib, n_sp, tree_state = _grow_tree(
                 cfg, bins, g_all[:, c], h_all[:, c], edges, tree_state, reduce_fn
             )
-            t_idx = r * C + c
-            t_feat, t_thr, t_split, lref, t_gain, c_leaf = tree
-            new["feature"] = jax.lax.dynamic_update_slice_in_dim(
-                new["feature"], t_feat[None], t_idx, axis=0
-            )
-            new["thr_bin"] = jax.lax.dynamic_update_slice_in_dim(
-                new["thr_bin"], t_thr[None], t_idx, axis=0
-            )
-            new["is_split"] = jax.lax.dynamic_update_slice_in_dim(
-                new["is_split"], t_split[None], t_idx, axis=0
-            )
-            new["leaf_ref"] = jax.lax.dynamic_update_slice_in_dim(
-                new["leaf_ref"], lref[None], t_idx, axis=0
-            )
-            new["node_gain"] = jax.lax.dynamic_update_slice_in_dim(
-                new["node_gain"], t_gain[None], t_idx, axis=0
-            )
-            new["leaf_cnt"] = jax.lax.dynamic_update_slice_in_dim(
-                new["leaf_cnt"], c_leaf[None], t_idx, axis=0
-            )
-            contribs.append(contrib)
-            round_splits = round_splits + n_sp
-        (
-            new["used_feat"],
-            new["used_thr"],
-            new["leaf_values"],
-            new["n_leaf"],
-            _,
-            _,
-        ) = tree_state
-        new["preds"] = state["preds"] + jnp.stack(contribs, axis=1)
-        new["n_splits"] = state["n_splits"] + round_splits
-        new["n_trees"] = state["n_trees"] + C
+            with jax.named_scope(UPDATE):
+                t_idx = r * C + c
+                t_feat, t_thr, t_split, lref, t_gain, c_leaf = tree
+                new["feature"] = jax.lax.dynamic_update_slice_in_dim(
+                    new["feature"], t_feat[None], t_idx, axis=0
+                )
+                new["thr_bin"] = jax.lax.dynamic_update_slice_in_dim(
+                    new["thr_bin"], t_thr[None], t_idx, axis=0
+                )
+                new["is_split"] = jax.lax.dynamic_update_slice_in_dim(
+                    new["is_split"], t_split[None], t_idx, axis=0
+                )
+                new["leaf_ref"] = jax.lax.dynamic_update_slice_in_dim(
+                    new["leaf_ref"], lref[None], t_idx, axis=0
+                )
+                new["node_gain"] = jax.lax.dynamic_update_slice_in_dim(
+                    new["node_gain"], t_gain[None], t_idx, axis=0
+                )
+                new["leaf_cnt"] = jax.lax.dynamic_update_slice_in_dim(
+                    new["leaf_cnt"], c_leaf[None], t_idx, axis=0
+                )
+                contribs.append(contrib)
+                round_splits = round_splits + n_sp
+        with jax.named_scope(UPDATE):
+            (
+                new["used_feat"],
+                new["used_thr"],
+                new["leaf_values"],
+                new["n_leaf"],
+                _,
+                _,
+            ) = tree_state
+            new["preds"] = state["preds"] + jnp.stack(contribs, axis=1)
+            new["n_splits"] = state["n_splits"] + round_splits
+            new["n_trees"] = state["n_trees"] + C
 
-        bits = toad_bits(
-            new["used_feat"],
-            new["used_thr"],
-            new["n_leaf"],
-            new["n_trees"],
-            new["n_splits"],
-            edges,
-            D,
-            C,
-        )
-        mem_ok = (budget <= 0) | (bits.astype(jnp.float32) <= budget * 8.0)
-        accept = (~state["stopped"]) & (round_splits > 0) & mem_ok
-        merged = jax.tree.map(
-            lambda a, b: jnp.where(accept, a, b), new, state
-        )
-        merged["stopped"] = state["stopped"] | ~accept
-        hist_out = dict(
-            bytes=bits.astype(jnp.float32) / 8.0,
-            accepted=accept,
-            n_fu=jnp.sum(merged["used_feat"].astype(jnp.int32)),
-            n_thr=jnp.sum(merged["used_thr"].astype(jnp.int32)),
-            n_leaf=merged["n_leaf"],
-            n_splits=merged["n_splits"],
-        )
+            bits = toad_bits(
+                new["used_feat"],
+                new["used_thr"],
+                new["n_leaf"],
+                new["n_trees"],
+                new["n_splits"],
+                edges,
+                D,
+                C,
+            )
+            mem_ok = (budget <= 0) | (bits.astype(jnp.float32) <= budget * 8.0)
+            accept = (~state["stopped"]) & (round_splits > 0) & mem_ok
+            merged = jax.tree.map(
+                lambda a, b: jnp.where(accept, a, b), new, state
+            )
+            merged["stopped"] = state["stopped"] | ~accept
+            hist_out = dict(
+                bytes=bits.astype(jnp.float32) / 8.0,
+                accepted=accept,
+                n_fu=jnp.sum(merged["used_feat"].astype(jnp.int32)),
+                n_thr=jnp.sum(merged["used_thr"].astype(jnp.int32)),
+                n_leaf=merged["n_leaf"],
+                n_splits=merged["n_splits"],
+            )
         return merged, hist_out
 
     final, history = jax.lax.scan(round_body, state0, jnp.arange(M, dtype=jnp.int32))

@@ -1,4 +1,5 @@
-"""Compile-only checks: the main path's Pallas kernels lower for a TPU v5e.
+"""Compile-only checks: the main path's Pallas kernels lower for a TPU v5e,
+and the trainer's phase scopes survive the v5e compiler.
 
 The TPU compiler is installed even where no chip is attached: a described
 ``v5e:2x2`` topology lets ``jit(...).lower(...).compile()`` run Mosaic on
@@ -14,8 +15,10 @@ so test workers that never run this file never load the TPU library.
 
 from __future__ import annotations
 
+import collections
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +26,8 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.gbdt import trainer
+from repro.kernels import ops
 from repro.kernels.binning import binning
 from repro.kernels.histogram import histogram
 from repro.kernels.predict import _packed_predict_ee_call, packed_predict
@@ -119,3 +124,83 @@ def test_shapes_are_real_widths():
     cfg = config()
     assert (cfg.n_features, cfg.n_bins, cfg.gbdt.max_depth) == (D, N_BINS, DEPTH)
     assert np.log2(ROWS) % 1 == 0
+
+
+# --- the trainer's phase scopes survive the v5e compiler -------------------
+
+#: ops that ``lax.scan``'s body calls ``round_body`` through
+ROUND = "jit(train)/while/body/closed_call/"
+#: instruction kinds that do a round's work: each must name its phase
+PHASE_KINDS = {"fusion", "custom-call", "gather", "scatter", "reduce",
+               "dynamic-update-slice"}
+#: what runs in the trainer's loops without a phase: XLA's loop-boundary
+#: copies, layouts and async copies (no metadata), the TPU rewrite of the
+#: cumulative sums (``op_name="reduce_window_sum"``), the call boundary of
+#: ``round_body``, and the scan's own counter and stacked per-round outputs
+UNSCOPED_KINDS = {
+    "add", "and", "bitcast", "broadcast", "compare", "constant", "copy",
+    "copy-done", "copy-start", "dynamic-slice", "dynamic-update-slice",
+    "fusion", "get-tuple-element", "parameter", "reduce-window", "reshape",
+    "select", "slice", "tuple",
+}
+
+
+def _executed(hlo_text: str):
+    """(entry name, {computation: [(instruction, kind, op_name)]}) of the
+    entry computation and every while-loop body and condition it reaches:
+    the computations whose instructions run as device operations."""
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(ENTRY )?%([^\s(]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(2), {"entry": bool(head.group(1)),
+                                                   "ins": [], "calls": []})
+            continue
+        if line.startswith("}"):
+            cur = None
+            continue
+        ins = cur is not None and re.match(
+            r"^\s*(?:ROOT )?%([^\s=]+) = .*?\s([a-z][a-z0-9-]*)\(", line)
+        if ins:
+            op = re.search(r'op_name="([^"]*)"', line)
+            cur["ins"].append((ins.group(1), ins.group(2), op.group(1) if op else ""))
+            cur["calls"] += re.findall(r"\b(?:body|condition)=%([^\s,]+)", line)
+    entry = next(k for k, v in comps.items() if v["entry"])
+    todo, seen = [entry], {}
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen[c] = comps[c]["ins"]
+            todo += comps[c]["calls"]
+    return entry, seen
+
+
+def test_trainer_phase_scopes_compile(one_chip, monkeypatch):
+    """Every operation of a round names one phase of ``trainer.PHASES`` in the
+    v5e program, and the Pallas call keeps its name ``histogram``."""
+    monkeypatch.setattr(ops, "_interp", lambda: False)
+    jax.clear_caches()  # trace anew, with the kernel compiled for the chip
+    cfg = trainer.GBDTConfig(task="binary", n_rounds=2, max_depth=4,
+                             hist_method="pallas", toad_penalty_feature=8.0,
+                             toad_penalty_threshold=2.0)
+    shapes = [((ROWS, 28), jnp.int32), ((ROWS,), jnp.float32),
+              ((28, N_BINS - 1), jnp.float32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    text = trainer.train_jit.lower(cfg, *args).compile().as_text()
+    entry, executed = _executed(text)
+
+    unnamed, seen_phases, unscoped = [], set(), collections.Counter()
+    for comp, instructions in executed.items():
+        for name, kind, op in instructions:
+            scopes = [p for p in op.split("/") if p.startswith("toad.")]
+            seen_phases.update(scopes)
+            if kind in PHASE_KINDS and op.startswith(ROUND):
+                if len(scopes) != 1 or scopes[0] not in trainer.PHASES:
+                    unnamed.append((name, op))
+            elif comp != entry and not scopes:
+                unscoped[kind] += 1
+    assert not unnamed, unnamed[:10]
+    assert seen_phases == set(trainer.PHASES)
+    assert set(unscoped) <= UNSCOPED_KINDS, unscoped
+    names = [n for ins in executed.values() for n, _, _ in ins]
+    assert any(re.fullmatch(r"histogram(\.\d+)?", n) for n in names)
