@@ -95,6 +95,16 @@ def _kernel(bins_ref, gh_ref, pos_ref, colnode_ref, colsel_ref, out_ref, *,
             preferred_element_type=jnp.float32)
 
 
+def bins_on_lanes(bins):
+    """(n, d) bins -> the kernel's ``(d_pad, n_pad)`` int32 view, samples on
+    lanes: features padded to ``FEATURE_BLOCK``, samples to ``TILE``, both
+    with bin 0.  The trainer routes rows through the same expression, so
+    within one program XLA keeps a single copy for both."""
+    n, d = bins.shape
+    return jnp.pad(bins.astype(jnp.int32),
+                   ((0, -n % TILE), (0, -d % FEATURE_BLOCK))).T
+
+
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins", "interpret"))
 def histogram(bins, gh, pos, *, n_nodes: int, n_bins: int, interpret: bool = True):
     """(n, d) bins × (n, CH) channels × (n,) node ids -> (n_nodes, d, n_bins, CH).
@@ -110,7 +120,7 @@ def histogram(bins, gh, pos, *, n_nodes: int, n_bins: int, interpret: bool = Tru
     # samples on lanes throughout: bins (d, n), channels (CH, n), nodes
     # (1, n), so a grid step's blocks are (FEATURE_BLOCK, TILE), (CH, TILE)
     # and (1, TILE), and no array is padded out to 128 lanes in HBM
-    bins_t = jnp.pad(bins.astype(jnp.int32), ((0, n_pad), (0, d_pad))).T
+    bins_t = bins_on_lanes(bins)
     gh_t = jnp.pad(gh.astype(jnp.float32), ((0, n_pad), (0, 0))).T
     # padding rows carry the out-of-range sentinel: they contribute nothing
     pos = jnp.pad(pos.astype(jnp.int32), (0, n_pad), constant_values=n_nodes)

@@ -204,3 +204,69 @@ def test_trainer_phase_scopes_compile(one_chip, monkeypatch):
     assert set(unscoped) <= UNSCOPED_KINDS, unscoped
     names = [n for ins in executed.values() for n, _, _ in ins]
     assert any(re.fullmatch(r"histogram(\.\d+)?", n) for n in names)
+
+
+def _computations(hlo_text: str):
+    """{computation: [(kind, output type, op_name, called computations)]}
+    for every computation of the program, fused ones included."""
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([^\s(]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+            continue
+        if line.startswith("}"):
+            cur = None
+            continue
+        ins = cur is not None and re.match(
+            r"^\s*(?:ROOT )?%[^\s=]+ = (.*?) ([a-z][a-z0-9-]*)\(", line)
+        if ins:
+            op = re.search(r'op_name="([^"]*)"', line)
+            calls = re.findall(r"\b(?:calls|body|condition|to_apply)=%([^\s,]+)", line)
+            cur.append((ins.group(2), ins.group(1), op.group(1) if op else "", calls))
+    return comps
+
+
+def _elements(hlo_type: str) -> int:
+    """Elements of the largest array in an HLO output type."""
+    dims = re.findall(r"[a-z][a-z0-9]*\[([0-9,]*)\]", hlo_type)
+    return max((int(np.prod([int(x) for x in d.split(",") if x])) for d in dims),
+               default=0)
+
+
+def test_trainer_routes_without_gathers(one_chip, monkeypatch):
+    """At depth 8 the v5e trainer routes rows and reads their leaf values
+    without a per-row gather (``trainer._route``, ``trainer._contrib``), and
+    its compare-and-select fuses: no ``toad.route`` buffer outgrows the
+    ``(d_pad, ROWS)`` bins the histogram kernel reads."""
+    monkeypatch.setattr(ops, "_interp", lambda: False)
+    jax.clear_caches()
+    d = 28
+    cfg = trainer.GBDTConfig(task="binary", n_rounds=2, max_depth=DEPTH,
+                             hist_method="pallas", toad_penalty_feature=8.0,
+                             toad_penalty_threshold=2.0)
+    shapes = [((ROWS, d), jnp.int32), ((ROWS,), jnp.float32),
+              ((d, N_BINS - 1), jnp.float32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    text = trainer.train_jit.lower(cfg, *args).compile().as_text()
+    comps = _computations(text)
+
+    # a fused gather names its scope in the op_name of the fusion's caller
+    caller = {c: op for ins in comps.values() for _, _, op, calls in ins
+              for c in calls}
+    gathers = [(typ, op + " " + caller.get(comp, ""))
+               for comp, ins in comps.items() for kind, typ, op, _ in ins
+               if kind == "gather"]
+    assert not [g for g in gathers if trainer.ROUTE in g[1]], gathers
+    leaf = [g for g in gathers if trainer.LEAF in g[1]]
+    assert leaf and all(_elements(t) < ROWS for t, _ in leaf), leaf
+
+    # buffers are the outputs of the instructions that run, not of the
+    # instructions inside a fusion
+    _, executed = _executed(text)
+    route = [(typ, op) for c in executed for _, typ, op, _ in comps[c]
+             if trainer.ROUTE in op]
+    d_pad = -(-d // 8) * 8
+    assert route
+    assert all(_elements(t) <= d_pad * ROWS for t, _ in route), max(
+        route, key=lambda r: _elements(r[0]))
